@@ -23,7 +23,7 @@ def run(verbose=True):
         probs = jax.nn.softmax(jax.random.normal(key, (B, V)))
         seeds = jax.random.bits(key, (B,), dtype=jnp.uint32)
         t, (tok_k, _) = common.timer(
-            lambda: ops.gumbel_argmax(probs, seeds))
+            lambda: ops.gumbel_argmax(probs, seeds, interpret=True))
         t_ref, (tok_r, _) = common.timer(
             lambda: jax.jit(ref.gumbel_argmax_ref)(probs, seeds))
         match = bool(np.array_equal(np.asarray(tok_k), np.asarray(tok_r)))
@@ -31,7 +31,7 @@ def run(verbose=True):
                      "us_per_call": round(t * 1e6, 1),
                      "ref_us": round(t_ref * 1e6, 1), "exact": match})
         t, (d_k,) = common.timer(
-            lambda: (ops.tournament(probs, seeds, m=30),))
+            lambda: (ops.tournament(probs, seeds, m=30, interpret=True),))
         t_ref, (d_r,) = common.timer(
             lambda: (jax.jit(lambda p, s: ref.tournament_ref(p, s, m=30))(
                 probs, seeds),))
@@ -46,7 +46,8 @@ def run(verbose=True):
     toks = jax.random.randint(jax.random.key(3), (B, K), 0, V)
     u = jax.random.uniform(jax.random.key(4), (B, K))
     seeds = jax.random.bits(jax.random.key(5), (B, K), dtype=jnp.uint32)
-    t, outs_k = common.timer(lambda: ops.spec_verify(p, q, toks, u, seeds))
+    t, outs_k = common.timer(
+        lambda: ops.spec_verify(p, q, toks, u, seeds, interpret=True))
     t_ref, outs_r = common.timer(
         lambda: jax.jit(ref.spec_verify_ref)(p, q, toks, u, seeds))
     match = all(np.allclose(np.asarray(a), np.asarray(b), rtol=1e-6)

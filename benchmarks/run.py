@@ -25,6 +25,8 @@ def main() -> None:
                          "at reduced sizes (produces kernels_bench.json "
                          "and spec_step_bench.json)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     only = set(args.only.split(",")) if args.only else None
     if args.quick:
         only = {"kernels", "spec_step", "spec_step_keyed", "paged_decode",

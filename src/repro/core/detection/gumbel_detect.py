@@ -31,17 +31,24 @@ def ars_score(y: np.ndarray) -> float:
     return float((np.sum(-np.log(1.0 - y)) - n) / np.sqrt(n))
 
 
+def _select(rec: SeqRecord, pick_draft: np.ndarray) -> np.ndarray:
+    """Per token, the draft-stream statistic where ``pick_draft`` holds,
+    else the target one; (n,) picks broadcast over (n, m) vector stats."""
+    pick = pick_draft.reshape(pick_draft.shape
+                              + (1,) * (rec.y_draft.ndim - 1))
+    return np.where(pick, rec.y_draft, rec.y_target)
+
+
 def select_tau(rec: SeqRecord, tau: float) -> np.ndarray:
-    return np.where(rec.u < tau, rec.y_draft, rec.y_target)
+    return _select(rec, rec.u < tau)
 
 
 def select_prior(rec: SeqRecord, p: float, rng: np.random.Generator):
-    pick_draft = rng.uniform(size=rec.u.shape) < p
-    return np.where(pick_draft, rec.y_draft, rec.y_target)
+    return _select(rec, rng.uniform(size=rec.u.shape) < p)
 
 
 def select_oracle(rec: SeqRecord) -> np.ndarray:
-    return np.where(rec.src == 1, rec.y_draft, rec.y_target)
+    return _select(rec, rec.src == 1)
 
 
 def scores_tau(records: Sequence[SeqRecord], tau: float, n_tokens: int):

@@ -37,7 +37,9 @@ def _hash_u32(x):
 
 def _uniform(seed, counter):
     bits = _hash_u32(seed * _MIX ^ _hash_u32(counter))
-    return (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(
+    # via int32 (exact: the value is < 2^24) — Mosaic has no u32->f32 cast
+    return (bits >> np.uint32(8)).astype(jnp.int32).astype(
+        jnp.float32) * np.float32(
         1.0 / (1 << 24)) + np.float32(1.0 / (1 << 25))
 
 

@@ -1,9 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode — the
-kernel body executes in Python per grid step, validating the exact program
-that ``pl.pallas_call`` would stage for TPU.  On a real TPU backend the same
-call compiles to Mosaic.
+Dispatch (``kernel_mode``): on a TPU backend every wrapper stages the
+Mosaic kernel; on CPU it runs the kernel's bit-exact jnp mirror
+(``kernels.ref``); any other backend raises.  The Pallas interpreter runs
+only when a caller passes ``interpret=True`` (kernel validation in the
+tests and the CPU kernel benchmark).
 """
 from __future__ import annotations
 
@@ -12,6 +13,7 @@ from functools import partial
 import jax
 
 from repro.core import prf as _prf
+from repro.kernels import ref as _ref
 from repro.kernels.gumbel_argmax import gumbel_argmax_kernel
 from repro.kernels.spec_verify import (spec_verify_kernel,
                                        spec_verify_wm_kernel)
@@ -28,24 +30,41 @@ DEFAULT_STREAMS = (_prf.STREAM_TARGET, _prf.STREAM_PLAIN + 2,
                    _prf.STREAM_PLAIN + _prf.STREAM_TARGET)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def kernel_mode(interpret: bool | None = None) -> str:
+    """How a kernel call runs: ``"interpret"`` when the caller passes
+    interpret=True, ``"mosaic"`` on a TPU backend (or when the caller
+    passes interpret=False), ``"mirror"`` — the bit-exact jnp reference —
+    on CPU.  Any other backend raises: no kernel falls back silently."""
+    if interpret is not None:
+        return "interpret" if interpret else "mosaic"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return "mosaic"
+    if backend == "cpu":
+        return "mirror"
+    raise RuntimeError(
+        f"no kernel path for backend {backend!r}: the Pallas kernels "
+        "compile for TPU (Mosaic) and run their jnp mirrors on CPU")
 
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def gumbel_argmax(probs, seeds, *, block_rows: int = 4,
                   interpret: bool | None = None):
-    interpret = _interpret_default() if interpret is None else interpret
+    mode = kernel_mode(interpret)
+    if mode == "mirror":
+        return _ref.gumbel_argmax_ref(probs, seeds)
     return gumbel_argmax_kernel(probs, seeds, block_rows=block_rows,
-                                interpret=interpret)
+                                interpret=mode == "interpret")
 
 
 @partial(jax.jit, static_argnames=("m", "block_rows", "interpret"))
 def tournament(probs, seeds, *, m: int = 30, block_rows: int = 4,
                interpret: bool | None = None):
-    interpret = _interpret_default() if interpret is None else interpret
+    mode = kernel_mode(interpret)
+    if mode == "mirror":
+        return _ref.tournament_ref(probs, seeds, m=m)
     return tournament_kernel(probs, seeds, m=m, block_rows=block_rows,
-                             interpret=interpret)
+                             interpret=mode == "interpret")
 
 
 @partial(jax.jit, static_argnames=("stream", "m", "block_rows",
@@ -55,22 +74,23 @@ def tournament_keyed(probs, keys, ctx_hashes, *, stream: int, m: int = 30,
     """Per-row keyed tournament: g-seeds derived in-kernel from the (B,)
     key-word row (multi-tenant batches).  CPU default is the bit-exact jnp
     mirror (``ref.tournament_keyed_ref``)."""
-    if interpret is None and _interpret_default():
-        from repro.kernels import ref as _ref
+    mode = kernel_mode(interpret)
+    if mode == "mirror":
         return _ref.tournament_keyed_ref(probs, keys, ctx_hashes,
                                          stream=stream, m=m)
-    interpret = False if interpret is None else interpret
     return tournament_keyed_kernel(probs, keys, ctx_hashes, stream=stream,
                                    m=m, block_rows=block_rows,
-                                   interpret=interpret)
+                                   interpret=mode == "interpret")
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def spec_verify(p, q, draft_tokens, u, resid_seeds, *,
                 interpret: bool | None = None):
-    interpret = _interpret_default() if interpret is None else interpret
+    mode = kernel_mode(interpret)
+    if mode == "mirror":
+        return _ref.spec_verify_ref(p, q, draft_tokens, u, resid_seeds)
     return spec_verify_kernel(p, q, draft_tokens, u, resid_seeds,
-                              interpret=interpret)
+                              interpret=mode == "interpret")
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -82,37 +102,36 @@ def wkv_step(r, k, v, w, u, s0, *, interpret: bool | None = None):
     mirror (``ref.wkv_step_ref`` — parity enforced by tests), which is
     also literally one step of the ``wkv_ref`` scan, so decode splices
     bit-exactly onto a chunked-scan prefill prefix."""
-    if interpret is None and _interpret_default():
-        from repro.kernels import ref as _ref
+    mode = kernel_mode(interpret)
+    if mode == "mirror":
         return _ref.wkv_step_ref(r, k, v, w, u, s0)
-    interpret = False if interpret is None else interpret
-    return wkv_step_kernel(r, k, v, w, u, s0, interpret=interpret)
+    return wkv_step_kernel(r, k, v, w, u, s0,
+                           interpret=mode == "interpret")
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def ssd_step(la, dtx, Bf, Cf, h0, *, interpret: bool | None = None):
     """Fused single-step SSD decode — fused-for-decode counterpart of the
     chunked ``ssd`` kernel (same dispatch contract as ``wkv_step``)."""
-    if interpret is None and _interpret_default():
-        from repro.kernels import ref as _ref
+    mode = kernel_mode(interpret)
+    if mode == "mirror":
         return _ref.ssd_step_ref(la, dtx, Bf, Cf, h0)
-    interpret = False if interpret is None else interpret
-    return ssd_step_kernel(la, dtx, Bf, Cf, h0, interpret=interpret)
+    return ssd_step_kernel(la, dtx, Bf, Cf, h0,
+                           interpret=mode == "interpret")
 
 
 def _spec_verify_wm_local(p, q, draft_tokens, u, keys, ctx_hashes,
                           seen, live, *, streams, tail,
                           interpret: bool | None):
     """Single-shard body of ``spec_verify_wm`` (grid spans the local batch)."""
-    if interpret is None and _interpret_default():
-        from repro.kernels import ref as _ref
+    mode = kernel_mode(interpret)
+    if mode == "mirror":
         return _ref.spec_verify_wm_ref(p, q, draft_tokens, u, keys,
                                        ctx_hashes, seen, live,
                                        streams=streams, tail=tail)
-    interpret = False if interpret is None else interpret
     return spec_verify_wm_kernel(p, q, draft_tokens, u, keys, ctx_hashes,
                                  seen, live, streams=streams,
-                                 tail=tail, interpret=interpret)
+                                 tail=tail, interpret=mode == "interpret")
 
 
 @partial(jax.jit, static_argnames=("interpret", "mesh", "batch_axes",
@@ -160,7 +179,6 @@ def spec_verify_wm(p, q, draft_tokens, u, keys, ctx_hashes, seen,
                                      streams=streams, tail=tail,
                                      interpret=interpret)
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     B, K1 = ctx_hashes.shape
     if live is None:
@@ -168,6 +186,6 @@ def spec_verify_wm(p, q, draft_tokens, u, keys, ctx_hashes, seen,
     spec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0])
     fn = partial(_spec_verify_wm_local, streams=streams, tail=tail,
                  interpret=interpret)
-    return shard_map(fn, mesh=mesh, in_specs=(spec,) * 8,
-                     out_specs=(spec,) * 4, check_rep=False)(
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 8,
+                         out_specs=(spec,) * 4, check_vma=False)(
         p, q, draft_tokens, u, keys, ctx_hashes, seen, live)

@@ -220,13 +220,14 @@ def ssd_step_ref(la, dtx, Bf, Cf, h0):
 def paged_gather(pool, page_table):
     """Materialize a slot-major dense view of a paged KV pool.
 
-    pool (P, page_size, Hkv, hd), page_table (B, max_pages) physical page
+    pool (P, Hkv, page_size, hd), page_table (B, max_pages) physical page
     ids -> (B, max_pages * page_size, Hkv, hd), logical position order.
     Null-page (id 0) tails gather garbage at logical positions >= the
     slot's allocation, which the position gate masks before the softmax."""
     B, n_pages = page_table.shape
-    page_size = pool.shape[1]
-    return pool[page_table].reshape((B, n_pages * page_size) + pool.shape[2:])
+    _, Hkv, page_size, hd = pool.shape
+    pages = pool[page_table].transpose(0, 1, 3, 2, 4)  # (B, n, ps, Hkv, hd)
+    return pages.reshape(B, n_pages * page_size, Hkv, hd)
 
 
 def paged_attention_ref(q, k_pool, v_pool, page_table, pos, *, window=0,

@@ -47,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.gumbel_argmax import _seed_chain, _uniform
 from repro.kernels.tournament import _gbit
@@ -128,63 +129,111 @@ def spec_verify_kernel(p, q, draft_tokens, u, resid_seeds, *,
     return n_acc[:, 0], acc, rtok[:, 0], ru[:, 0]
 
 
-def _wm_kernel(p_ref, q_ref, tok_ref, u_ref, key_ref, ctx_ref,
-               seen_ref, live_ref, nacc_ref, acc_ref, etok_ref, estat_ref,
+_LANES = 128
+
+
+def _lanes(n: int = _LANES):
+    """(1, n) int32 lane iota — Mosaic lowers 2D iotas only."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+
+def _first_argmax(score):
+    """``jnp.argmax`` of a (1, n) row (first maximal lane) as a (1, 1)
+    int32, written as max + masked min — reductions Mosaic lowers."""
+    n = score.shape[-1]
+    best = jnp.max(score, axis=-1, keepdims=True)
+    return jnp.min(jnp.where(score == best, _lanes(n), n), axis=-1,
+                   keepdims=True)
+
+
+def _pick(onehot, row):
+    """Sum of ``row`` over the lanes where ``onehot`` holds: the value at
+    the single selected lane (exact — every other term is +0)."""
+    return jnp.sum(jnp.where(onehot, row, 0.0), axis=-1, keepdims=True)
+
+
+def _wm_vmem_bytes(K: int, vp: int, p_dtype, q_dtype) -> int:
+    """Scoped-VMEM budget of one ``_wm_kernel`` grid step: the
+    double-buffered (K+1, Vp) / (K, Vp) probability blocks (second-minor
+    dim padded to the dtype's sublane tile), 48 (1, Vp) f32 working rows
+    (the v5e compiler kept up to ~30 live at V = 256k inside the served
+    step) and 2 MiB of slack.  That exceeds the default scoped limit at
+    large vocabularies, so the kernel asks for it explicitly."""
+    def block(rows, dtype):
+        itemsize = jnp.dtype(dtype).itemsize
+        sub = 8 * (4 // itemsize)
+        return -(-rows // sub) * sub * vp * itemsize
+    return (2 * (block(K + 1, p_dtype) + block(K, q_dtype))
+            + 48 * vp * 4 + (2 << 20))
+
+
+def _wm_kernel(tok_ref, u_ref, key_ref, ctx_ref, seen_ref, live_ref,
+               p_ref, q_ref, nacc_ref, acc_ref, etok_ref, estat_ref,
                *, K: int, vocab: int, kind: str, m: int, degenerate: bool,
-               stat_dim: int, wm_stream: int, plain_resid: int,
-               plain_bonus: int, draw_stream: int):
+               wm_stream: int, plain_resid: int, plain_bonus: int,
+               draw_stream: int):
+    """One grid step = one sequence row ``i``.  The per-row scalars
+    (draft tokens, coins, key word, context hashes, seen and live flags)
+    are SMEM scalars indexed by the row; the probability rows are the
+    row's (K+1, Vp) / (K, Vp) VMEM blocks; every output is a lane-dense
+    (1, 128)-padded row sliced by the wrapper."""
+    i = pl.program_id(0)
+    lane = _lanes()
     # Zero-init so non-live (drained continuous-batching slot) rows emit
     # defined outputs; the whole verification/race body is then predicated
     # off for them — a drained row costs no gather/race work on TPU.
-    nacc_ref[0] = jnp.zeros((1,), jnp.int32)
-    acc_ref[0] = jnp.zeros((K,), jnp.int32)
-    etok_ref[0] = jnp.zeros((1,), jnp.int32)
-    estat_ref[0] = jnp.zeros((stat_dim,), jnp.float32)
+    nacc_ref[0] = jnp.zeros((1, _LANES), jnp.int32)
+    acc_ref[0] = jnp.zeros((1, _LANES), jnp.int32)
+    etok_ref[0] = jnp.zeros((1, _LANES), jnp.int32)
+    estat_ref[0] = jnp.zeros(estat_ref.shape[1:], jnp.float32)
 
-    @pl.when(live_ref[0, 0] != 0)
+    @pl.when(live_ref[i] != 0)
     def _():
-        p = p_ref[0].astype(jnp.float32)    # (K+1, Vp): slot K = bonus dist
-        q = q_ref[0].astype(jnp.float32)    # (K, Vp)
-        toks = tok_ref[0]                   # (K,)
-        u = u_ref[0].astype(jnp.float32)    # (K,) acceptance coins
-        key = key_ref[0, 0].astype(jnp.uint32)   # this row's key word
-        ctx = ctx_ref[0].astype(jnp.uint32)      # (K+1,) context hashes
-        seen = seen_ref[0]                  # (K+1,) int32 repeated-ctx mask
-        kv, vp = q.shape
-        w2 = jax.lax.broadcasted_iota(jnp.int32, (kv, vp), 1)
-        onehot = (w2 == toks[:, None]).astype(jnp.float32)
-        p_tok = jnp.sum(p[:K] * onehot, axis=-1)  # (K,)
-        q_tok = jnp.sum(q * onehot, axis=-1)
-        a = jnp.minimum(1.0, p_tok / jnp.maximum(q_tok, 1e-30))
-        prefix = jnp.cumprod((u < a).astype(jnp.int32))
-        n_acc = jnp.sum(prefix)
-        acc_ref[0] = prefix
-        nacc_ref[0] = n_acc.astype(jnp.int32)[None]
+        vp = q_ref.shape[-1]
+        wl = _lanes(vp)
+        wv = wl.astype(jnp.uint32)
+        rows_p = [p_ref[0, k:k + 1, :].astype(jnp.float32)
+                  for k in range(K + 1)]     # slot K = bonus dist
+        rows_q = [q_ref[0, k:k + 1, :].astype(jnp.float32)
+                  for k in range(K)]
+        # prefix acceptance: slot k is accepted iff every coin up to k
+        # passes u_s < min(1, p/q) — a running AND over the K slots
+        alive = jnp.ones((1, 1), jnp.bool_)
+        n_acc = jnp.zeros((1, 1), jnp.int32)
+        for k in range(K):
+            hit = wl == tok_ref[i, k]
+            a = jnp.minimum(1.0, _pick(hit, rows_p[k])
+                            / jnp.maximum(_pick(hit, rows_q[k]), 1e-30))
+            alive = alive & (u_ref[i, k] < a)
+            n_acc = n_acc + alive.astype(jnp.int32)
+        acc_ref[0] = (lane < n_acc).astype(jnp.int32)
+        nacc_ref[0] = jnp.broadcast_to(n_acc, (1, _LANES))
 
         # the single emitted extra token comes from slot n_acc in [0, K]:
         # for n_acc < K its base row is (p − q)_+ (first-rejection
-        # residual); for n_acc == K the q mask selects nothing, so
-        # r == p_K (bonus).
+        # residual); for n_acc == K no q row is selected, so r == p_K
+        # (bonus).
         slot = n_acc
-        rows_p = jax.lax.broadcasted_iota(jnp.int32, (K + 1, 1), 0)
-        p_s = jnp.sum(p * (rows_p == slot).astype(jnp.float32),
-                      axis=0, keepdims=True)           # (1, Vp)
-        rows_q = jax.lax.broadcasted_iota(jnp.int32, (kv, 1), 0)
-        q_s = jnp.sum(q * (rows_q == slot).astype(jnp.float32),
-                      axis=0, keepdims=True)
-        seen_s = jnp.sum(jnp.where(rows_p[:, 0] == slot, seen, 0))
-        # per-slot PRF seeds, re-derived in VMEM from the row's key word:
-        # select the slot's context hash, then chain stream -> context.
-        # The key->stream links are per-row constants; only the final ctx
-        # link depends on the selected slot.  The plain stream differs for
-        # the bonus slot (slot == K) vs a residual slot.
-        ctx_s = jnp.sum(jnp.where(rows_p[:, 0] == slot, ctx, jnp.uint32(0)))
+        p_s = jnp.zeros((1, vp), jnp.float32)
+        q_s = jnp.zeros((1, vp), jnp.float32)
+        ctx_s = jnp.zeros((1, 1), jnp.uint32)
+        seen_s = jnp.zeros((1, 1), jnp.int32)
+        for k in range(K + 1):
+            at = slot == k
+            p_s = jnp.where(at, rows_p[k], p_s)
+            if k < K:
+                q_s = jnp.where(at, rows_q[k], q_s)
+            ctx_s = jnp.where(at, ctx_ref[i, k], ctx_s)
+            seen_s = jnp.where(at, seen_ref[i, k], seen_s)
+        # per-slot PRF seeds, re-derived from the row's key word: chain
+        # stream -> context.  The plain stream differs for the bonus slot
+        # (slot == K) vs a residual slot.
+        key = key_ref[i]
         pl_stream = jnp.where(slot == K, jnp.uint32(plain_bonus),
                               jnp.uint32(plain_resid))
         wm_s = _seed_chain(_seed_chain(key, jnp.uint32(wm_stream)), ctx_s)
         pl_s = _seed_chain(_seed_chain(key, pl_stream), ctx_s)
         r = jnp.maximum(p_s - q_s, 0.0)
-        wv = jax.lax.broadcasted_iota(jnp.uint32, (1, vp), 1)
 
         if kind == "race":
             # Gumbel-max race over the raw row (scale-invariant, so the
@@ -193,12 +242,10 @@ def _wm_kernel(p_ref, q_ref, tok_ref, u_ref, key_ref, ctx_ref,
             seed_s = jnp.where(seen_s != 0, pl_s, wm_s)
             uv = _uniform(seed_s, wv)
             score = jnp.log(uv) / jnp.maximum(r, 1e-30)
-            score = jnp.where((r > 0) & (wv < vocab), score, -jnp.inf)
-            etok = jnp.argmax(score).astype(jnp.int32)  # flat over (1, Vp)
-            etok_ref[0] = etok[None]
-            estat_ref[0] = jnp.sum(
-                uv * (wv == etok.astype(jnp.uint32)).astype(jnp.float32)
-                )[None]
+            score = jnp.where((r > 0) & (wl < vocab), score, -jnp.inf)
+            etok = _first_argmax(score)
+            estat_ref[0] = jnp.broadcast_to(_pick(wl == etok, uv),
+                                            estat_ref.shape[1:])
         else:                       # kind == "tournament" (SynthID)
             # the tournament operator is not scale-invariant: normalize
             # the row at the padded-lane extent (the canon every jnp
@@ -206,13 +253,13 @@ def _wm_kernel(p_ref, q_ref, tok_ref, u_ref, key_ref, ctx_ref,
             # VMEM-resident with the tournament_kernel round body.
             dw_s = _seed_chain(_seed_chain(key, jnp.uint32(draw_stream)),
                                ctx_s)
-            z = jnp.sum(r)
+            z = jnp.sum(r, axis=-1, keepdims=True)
             rn = r / jnp.maximum(z, 1e-30)             # (1, Vp)
 
-            def round_body(i, pz):
-                g = _gbit(wm_s, wv + jnp.uint32(vocab) * i.astype(
+            def round_body(j, pz):
+                g = _gbit(wm_s, wv + jnp.uint32(vocab) * j.astype(
                     jnp.uint32))
-                mass_one = jnp.sum(pz * g)
+                mass_one = jnp.sum(pz * g, axis=-1, keepdims=True)
                 return pz * (1.0 + g - mass_one)
 
             pz = jax.lax.fori_loop(0, m, round_body, rn)
@@ -223,22 +270,19 @@ def _wm_kernel(p_ref, q_ref, tok_ref, u_ref, key_ref, ctx_ref,
             race_seed = jnp.where(seen_s != 0, pl_s, dw_s)
             uv = _uniform(race_seed, wv)
             score = jnp.log(uv) / jnp.maximum(race_dist, 1e-30)
-            score = jnp.where((race_dist > 0) & (wv < vocab), score,
+            score = jnp.where((race_dist > 0) & (wl < vocab), score,
                               -jnp.inf)
-            race_tok = jnp.argmax(score).astype(jnp.int32)
+            etok = _first_argmax(score)
             if degenerate:
-                arg_tok = jnp.argmax(
-                    jnp.where(wv < vocab, pz, -jnp.inf)).astype(jnp.int32)
-                etok = jnp.where(seen_s != 0, race_tok, arg_tok)
-            else:
-                etok = race_tok
-            etok_ref[0] = etok[None]
+                arg_tok = _first_argmax(jnp.where(wl < vocab, pz, -jnp.inf))
+                etok = jnp.where(seen_s != 0, etok, arg_tok)
             # m g-bit detection statistics of the emitted token under the
-            # zeta^T g-seed (counter tok + V*l — matches recover_stats)
-            li = jax.lax.broadcasted_iota(jnp.uint32, (1, stat_dim), 1)
-            g_tok = _gbit(wm_s, etok.astype(jnp.uint32)
-                          + jnp.uint32(vocab) * li)
-            estat_ref[0] = g_tok[0]
+            # zeta^T g-seed (counter tok + V*l — matches recover_stats);
+            # lanes >= m are sliced off by the wrapper
+            li = _lanes(estat_ref.shape[-1]).astype(jnp.uint32)
+            estat_ref[0] = _gbit(wm_s, etok.astype(jnp.uint32)
+                                 + jnp.uint32(vocab) * li)
+        etok_ref[0] = jnp.broadcast_to(etok, (1, _LANES))
 
 
 def spec_verify_wm_kernel(p, q, draft_tokens, u, keys, ctx_hashes,
@@ -280,48 +324,38 @@ def spec_verify_wm_kernel(p, q, draft_tokens, u, keys, ctx_hashes,
     B, K1, V = p.shape
     K = K1 - 1
     assert q.shape == (B, K, V), (p.shape, q.shape)
+    assert K < _LANES and tail.stat_dim <= _LANES, (K, tail.stat_dim)
     wm_stream, plain_resid, plain_bonus, draw_stream = (
         int(s) for s in streams)
     if live is None:
         live = jnp.ones((B,), jnp.int32)
-    vp = -(-V // 128) * 128
-    pp = jnp.zeros((B, K1, vp), p.dtype).at[:, :, :V].set(p)
-    qp = jnp.zeros((B, K, vp), q.dtype).at[:, :, :V].set(q)
+    vp = -(-V // _LANES) * _LANES
+    if vp != V:
+        p = jnp.zeros((B, K1, vp), p.dtype).at[:, :, :V].set(p)
+        q = jnp.zeros((B, K, vp), q.dtype).at[:, :, :V].set(q)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    row = pl.BlockSpec((1, 1, _LANES), lambda i: (i, 0, 0))
     outs = pl.pallas_call(
         functools.partial(_wm_kernel, K=K, vocab=V, kind=tail.kind,
                           m=tail.m, degenerate=tail.degenerate,
-                          stat_dim=tail.stat_dim, wm_stream=wm_stream,
-                          plain_resid=plain_resid, plain_bonus=plain_bonus,
-                          draw_stream=draw_stream),
+                          wm_stream=wm_stream, plain_resid=plain_resid,
+                          plain_bonus=plain_bonus, draw_stream=draw_stream),
         grid=(B,),
-        in_specs=[
+        in_specs=[smem] * 6 + [
             pl.BlockSpec((1, K1, vp), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, K, vp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, K), lambda i: (i, 0)),
-            pl.BlockSpec((1, K), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, K1), lambda i: (i, 0)),
-            pl.BlockSpec((1, K1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, K), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, tail.stat_dim), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, K), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, tail.stat_dim), jnp.float32),
-        ],
+        out_specs=[row] * 4,
+        out_shape=[jax.ShapeDtypeStruct((B, 1, _LANES), jnp.int32)] * 3
+        + [jax.ShapeDtypeStruct((B, 1, _LANES), jnp.float32)],
+        name="spec_verify_wm",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_wm_vmem_bytes(K, vp, p.dtype, q.dtype)),
         interpret=interpret,
-    )(pp, qp, draft_tokens.astype(jnp.int32), u.astype(jnp.float32),
-      keys.astype(jnp.uint32).reshape(B, 1),
-      ctx_hashes.astype(jnp.uint32), seen.astype(jnp.int32),
-      live.astype(jnp.int32).reshape(B, 1))
-    n_acc, acc, etok, estat = outs
+    )(draft_tokens.astype(jnp.int32), u.astype(jnp.float32),
+      keys.astype(jnp.uint32), ctx_hashes.astype(jnp.uint32),
+      seen.astype(jnp.int32), live.astype(jnp.int32), p, q)
+    n_acc, acc, etok, estat = (o[:, 0] for o in outs)
     if tail.kind == "race":
-        estat = estat[:, 0]
-    return n_acc[:, 0], acc, etok[:, 0], estat
+        return n_acc[:, 0], acc[:, :K], etok[:, 0], estat[:, 0]
+    return n_acc[:, 0], acc[:, :K], etok[:, 0], estat[:, :tail.stat_dim]

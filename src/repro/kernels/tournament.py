@@ -24,7 +24,7 @@ from repro.kernels.gumbel_argmax import _hash_u32, _MIX, _seed_chain
 
 def _gbit(seed, counter):
     bits = _hash_u32(seed * _MIX ^ _hash_u32(counter))
-    return (bits >> np.uint32(31)).astype(jnp.float32)
+    return (bits >> np.uint32(31)).astype(jnp.int32).astype(jnp.float32)
 
 
 def _rounds(p, seeds, w, *, m: int, vocab: int):

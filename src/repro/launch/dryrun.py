@@ -197,7 +197,7 @@ def lower_case(arch: str, shape_name: str, mesh, *, k_lookahead: int = 4,
             out_shardings=(jax.tree.map(lambda s: NS(mesh, s), p_spec),
                            jax.tree.map(lambda s: NS(mesh, s), o_spec),
                            None))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(specs["params"], specs["opt_state"],
                                    specs["batch"])
         return lowered
@@ -218,7 +218,7 @@ def lower_case(arch: str, shape_name: str, mesh, *, k_lookahead: int = 4,
                           jax.tree.map(lambda s: NS(mesh, s), b_spec)),
             out_shardings=(NS(mesh, l_spec),
                            jax.tree.map(lambda s: NS(mesh, s), c_spec)))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(specs["params"], specs["batch"])
         return lowered
 
@@ -237,7 +237,7 @@ def lower_case(arch: str, shape_name: str, mesh, *, k_lookahead: int = 4,
         cfg, dcfg, scfg, mesh, state_abs=specs["state"],
         t_shardings=jax.tree.map(lambda s: NS(mesh, s), p_spec),
         d_shardings=jax.tree.map(lambda s: NS(mesh, s), dp_spec))
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(specs["params"], specs["d_params"],
                                specs["state"])
     return lowered

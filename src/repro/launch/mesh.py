@@ -9,12 +9,10 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # AxisType landed after jax 0.4.x — pass it only where it exists.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes: shardings propagate through the SPMD partitioner from the
+    # jit in/out shardings (jax.make_mesh defaults to Explicit axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,8 +23,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1):
-    """Tiny mesh over whatever devices exist (CPU tests)."""
+    """A (data, model) mesh over the first data * model local devices.
+    Raises when fewer devices exist — a mesh is never silently shrunk."""
     n = len(jax.devices())
-    data = min(data, n)
-    model = max(1, min(model, n // data))
+    if data < 1 or model < 1 or data * model > n:
+        raise ValueError(
+            f"mesh data={data} x model={model} needs {data * model} "
+            f"devices; {n} present")
     return _make_mesh((data, model), ("data", "model"))

@@ -131,6 +131,9 @@ def main():
         raise SystemExit(subprocess.call(cmd, env=dict(
             os.environ, PYTHONPATH=os.environ.get("PYTHONPATH", "src"))))
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     import jax
     import numpy as np
 

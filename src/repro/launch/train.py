@@ -39,6 +39,9 @@ def main():
         raise SystemExit(subprocess.call(cmd, env=dict(
             os.environ, PYTHONPATH=os.environ.get("PYTHONPATH", "src"))))
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     from repro.checkpoint import io as ckpt
     from repro.configs import get_config, get_smoke_config
     from repro.data import synthetic

@@ -292,27 +292,28 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=0, grouped=False):
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *, window=0,
                            grouped=False):
-    """Decode attention through page indirection: pools (P, page_size,
-    Hkv, hd) + per-slot page tables (B, max_pages) of physical page ids
-    (0 = the reserved null page) replace the dense (B, S, Hkv, hd) cache.
+    """Decode attention through page indirection: pools (P, Hkv,
+    page_size, hd) + per-slot page tables (B, max_pages) of physical page
+    ids (0 = the reserved null page) replace the dense (B, S, Hkv, hd)
+    cache.
 
-    Dispatch lives in ``kernels.paged_attention``: the Pallas kernel on
-    TPU (page-table-driven block gathers in VMEM), the bit-exact jnp
-    mirror (gather + ``decode_attention``) on CPU — either way the output
-    is bit-identical to ``decode_attention`` over a dense cache holding
-    the same entries."""
+    Dispatch lives in ``kernels.paged_attention``: on CPU the jnp mirror
+    (gather + ``decode_attention``), bit-identical to ``decode_attention``
+    over a dense cache holding the same entries; on TPU the Pallas kernel
+    (page-table-driven block gathers, page-wise online softmax), equal to
+    it up to rounding."""
     from repro.kernels.paged_attention import paged_decode_attention as _pa
     return _pa(q, k_pool, v_pool, page_table, pos, window=window,
                grouped=grouped)
 
 
 def paged_cache_write(pool, page_table, kv, pos):
-    """Write kv (B, Sq, Hkv, hd) into a paged pool (P, page_size, Hkv, hd)
+    """Write kv (B, Sq, Hkv, hd) into a paged pool (P, Hkv, page_size, hd)
     at logical positions pos..pos+Sq-1 of each slot, routed through the
     slot's page-table row (B, max_pages).  Logical positions beyond the
     table (or on null-page tails) land in page 0, whose contents are
     position-gated out of every read."""
-    P_, page_size = pool.shape[:2]
+    page_size = pool.shape[2]
     B, Sq = kv.shape[:2]
     max_pages = page_table.shape[1]
     pos_b = jnp.broadcast_to(jnp.atleast_1d(pos), (B,))
@@ -320,11 +321,9 @@ def paged_cache_write(pool, page_table, kv, pos):
     lpage = idx // page_size
     phys = jnp.take_along_axis(page_table,
                                jnp.minimum(lpage, max_pages - 1), axis=1)
-    phys = jnp.where(lpage < max_pages, phys, 0)
-    flat_idx = phys * page_size + idx % page_size            # (B, Sq)
-    flat = pool.reshape((P_ * page_size,) + pool.shape[2:])
-    flat = flat.at[flat_idx].set(kv.astype(pool.dtype))
-    return flat.reshape(pool.shape)
+    phys = jnp.where(lpage < max_pages, phys, 0)             # (B, Sq)
+    # advanced indices around a slice: the indexed view is (B, Sq, Hkv, hd)
+    return pool.at[phys, :, idx % page_size].set(kv.astype(pool.dtype))
 
 
 def cache_write(cache, kv, pos):

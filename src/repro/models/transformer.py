@@ -275,7 +275,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
             "(image/audio frames) are per-request dense blocks, not "
             "token pages")
     hd = cfg.resolved_head_dim
-    pool = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, hd)
+    pool = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size, hd)
     return {
         "k": jnp.zeros(pool, dtype),
         "v": jnp.zeros(pool, dtype),
@@ -376,8 +376,8 @@ def _self_attn_step(p, cfg, x, cache_k, cache_v, pos):
 
 
 def _self_attn_step_paged(p, cfg, x, pool_k, pool_v, table, pos):
-    """Paged twin of ``_self_attn_step``: x (B,Sq,d); pools (P, page_size,
-    Hkv, hd); table (B, max_pages) physical page ids; pos (B,).  Reads and
+    """Paged twin of ``_self_attn_step``: x (B,Sq,d); pools (P, Hkv,
+    page_size, hd); table (B, max_pages) physical page ids; pos (B,).  Reads and
     appends go through the page indirection; the math (RoPE positions,
     position-gated masked softmax) is identical, so the output is
     bit-identical to the dense path over the same logical entries."""

@@ -777,7 +777,14 @@ def make_spec_step(tcfg: ModelConfig, dcfg: ModelConfig, scfg: SpecConfig,
             from_draft=from_draft, u=u, ctx_hashes=all_hashes,
             masked=all_seen, y_draft=y_d, y_target=y_t)
 
-    return step
+    if mesh is None:
+        return step
+
+    def step_on_mesh(*args, **kwargs):
+        with SHR.mesh_context(mesh):
+            return step(*args, **kwargs)
+
+    return step_on_mesh
 
 
 # ---------------------------------------------------------------------------

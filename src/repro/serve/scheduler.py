@@ -692,10 +692,12 @@ class Scheduler:
         if self.paged:
             # zeroed paged state: all-null page tables, pos 0 — slots fill
             # in place via chunked prefill + the jitted finalize
+            # the KV pools take the target params' dtype (bf16 weights
+            # -> bf16 pools), as the dense path's prefill caches do
             state = E.init_empty_paged_state(
                 tcfg, dcfg, scfg, batch, num_pages=self.num_pages,
                 page_size=self.page_size, max_pages=self.max_pages,
-                max_seq=self.max_seq)
+                max_seq=self.max_seq, cache_dtype=t_params["embed"].dtype)
         else:
             # a dummy prefill gives the state its shapes; every slot
             # starts FREE (done-masked), overwritten by its first admission
@@ -917,12 +919,13 @@ class Scheduler:
         their dense state rows (``models.ssm.extend_step``, padded tail
         identity-masked via ``n_valid``)."""
         state = carry["state"]
-        t_logits, t_cache = paged_slot_extend(
-            t_params, self.tcfg, toks, state["t_cache"], b, start_pos,
-            new_pos)
-        _, d_cache = self._drafter.slot_extend(
-            d_params, self.dcfg, toks, state["d_cache"], b, start_pos,
-            new_pos)
+        with E.SHR.mesh_context(self.mesh):
+            t_logits, t_cache = paged_slot_extend(
+                t_params, self.tcfg, toks, state["t_cache"], b, start_pos,
+                new_pos)
+            _, d_cache = self._drafter.slot_extend(
+                d_params, self.dcfg, toks, state["d_cache"], b, start_pos,
+                new_pos)
         state = dict(state, t_cache=t_cache, d_cache=d_cache)
         return dict(carry, state=state), t_logits
 

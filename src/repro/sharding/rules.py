@@ -19,6 +19,7 @@ cannot be split 16-way.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -177,21 +178,20 @@ def dp_axes(mesh: Mesh, global_batch: int) -> Optional[Tuple[str, ...]]:
 
 
 def _ambient_mesh():
-    """The mesh of the enclosing ``with mesh:`` context (legacy pjit env),
-    falling back to the new-style abstract mesh.  None when unset."""
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # pragma: no cover - API drift safety
-        pass
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        if m is not None and getattr(m, "axis_names", ()):
-            return m
-    return None
+    """The mesh set by an enclosing ``jax.set_mesh(mesh)`` or
+    ``mesh_context(mesh)``; None when unset."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
+
+
+def mesh_context(mesh):
+    """Context that makes ``mesh`` (abstractly) ambient while a jitted
+    function traces, so code deep in the model — the paged-attention
+    kernel's ``shard_map``, the ``constrain_*`` helpers — sees the mesh
+    the function is compiled for.  A no-op context for ``mesh=None``."""
+    if mesh is None:
+        return contextlib.nullcontext()
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
 
 
 def constrain_dims(x, entries: Sequence[Any]) -> Any:
@@ -290,13 +290,13 @@ def logits_spec(mesh: Mesh, *, global_batch: int, ndim: int = 3,
 #   with S-over-model as the fallback when kv-heads don't divide (heads get
 #   first claim via the priority vector).
 _KV = ([[DP], [TP], [TP], None], [0, 2, 1, 3])
-# block-paged pools (..., num_pages, page_size, Hkv, hd): pages are a
+# block-paged pools (..., num_pages, Hkv, page_size, hd): pages are a
 # *shared* arena — any slot's pages live anywhere in it, so the page dims
 # must stay replicated across dp (a dp-shard owns whole copies of the
 # pool for its slots' gathers); only kv-heads split, over "model".  The
 # page tables (B, max_pages) replicate per data shard: they are tiny
 # int32 and feed scalar-prefetch/gather indices on every shard.
-_PAGED_KV = ([None, None, [TP], None], None)
+_PAGED_KV = ([None, [TP], None, None], None)
 _CACHE_RULES: Dict[str, Tuple[List[Optional[AxisCandidates]],
                               Optional[List[int]]]] = {
     "k":   _KV,

@@ -135,3 +135,29 @@ def test_synthid_psi_fit_improves_likelihood():
     ll_fit = float(jnp.mean(synthid_detect.log_f1(psi, jnp.asarray(y))))
     ll_unif = float(m * np.log(0.5))
     assert ll_fit > ll_unif
+
+
+@pytest.mark.parametrize("m", [0, 4], ids=["scalar-stat", "g-bit-stat"])
+def test_stream_selection_broadcasts_over_vector_stats(m):
+    """The per-token draft/target stream pick (tau, prior and oracle
+    rules) selects whole (m,) g-bit rows for vector-stat schemes exactly as
+    it selects scalars for gumbel."""
+    rng = np.random.default_rng(0)
+    n = 6
+    shape = (n, m) if m else (n,)
+    rec = SeqRecord(tokens=np.arange(n, dtype=np.int32),
+                    y_draft=rng.uniform(size=shape),
+                    y_target=rng.uniform(size=shape) + 2.0,
+                    u=np.linspace(0.05, 0.95, n),
+                    src=(np.arange(n) % 2).astype(np.int8),
+                    watermarked=True)
+    picks = {"tau": rec.u < 0.5, "oracle": rec.src == 1}
+    got = {"tau": gumbel_detect.select_tau(rec, 0.5),
+           "oracle": gumbel_detect.select_oracle(rec)}
+    for rule, pick in picks.items():
+        assert got[rule].shape == shape
+        for t in range(n):
+            want = rec.y_draft[t] if pick[t] else rec.y_target[t]
+            np.testing.assert_array_equal(got[rule][t], want, err_msg=rule)
+    prior = gumbel_detect.select_prior(rec, 0.5, np.random.default_rng(1))
+    assert prior.shape == shape

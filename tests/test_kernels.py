@@ -28,7 +28,7 @@ def _seeds(seed, shape):
 def test_gumbel_argmax_sweep(B, V, dtype):
     probs = _probs(B * V, (B, V), dtype)
     seeds = _seeds(B + V, (B,))
-    tok_k, u_k = ops.gumbel_argmax(probs, seeds)
+    tok_k, u_k = ops.gumbel_argmax(probs, seeds, interpret=True)
     tok_r, u_r = ref.gumbel_argmax_ref(probs.astype(jnp.float32), seeds)
     assert np.array_equal(np.asarray(tok_k), np.asarray(tok_r))
     np.testing.assert_allclose(np.asarray(u_k), np.asarray(u_r), rtol=1e-6)
@@ -40,7 +40,7 @@ def test_gumbel_argmax_sweep(B, V, dtype):
 def test_tournament_sweep(B, V, m, dtype):
     probs = _probs(B + V + m, (B, V), dtype)
     seeds = _seeds(V + m, (B,))
-    d_k = ops.tournament(probs, seeds, m=m)
+    d_k = ops.tournament(probs, seeds, m=m, interpret=True)
     d_r = ref.tournament_ref(probs.astype(jnp.float32), seeds, m=m)
     np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r),
                                rtol=2e-3 if dtype == jnp.bfloat16 else 1e-5,
@@ -55,7 +55,7 @@ def test_spec_verify_sweep(B, K, V):
     toks = jax.random.randint(jax.random.key(B + K), (B, K), 0, V)
     u = jax.random.uniform(jax.random.key(K + V), (B, K))
     seeds = _seeds(B * K * V, (B, K))
-    outs_k = ops.spec_verify(p, q, toks, u, seeds)
+    outs_k = ops.spec_verify(p, q, toks, u, seeds, interpret=True)
     outs_r = ref.spec_verify_ref(p, q, toks, u, seeds)
     for a, b, nm in zip(outs_k, outs_r, ["n_acc", "acc", "rtok", "ru"]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
@@ -70,7 +70,7 @@ def test_kernel_gumbel_is_unbiased():
     n = 20000
     probs = jnp.broadcast_to(P, (n, V))
     seeds = jnp.arange(n, dtype=jnp.uint32)
-    toks, _ = ops.gumbel_argmax(probs, seeds, block_rows=64)
+    toks, _ = ops.gumbel_argmax(probs, seeds, block_rows=64, interpret=True)
     freq = np.bincount(np.asarray(toks), minlength=V) / n
     np.testing.assert_allclose(freq, np.asarray(P), atol=0.02)
 
@@ -81,7 +81,7 @@ def test_tournament_kernel_unbiased():
     n = 8000
     probs = jnp.broadcast_to(P, (n, V))
     seeds = jnp.arange(n, dtype=jnp.uint32)
-    d = ops.tournament(probs, seeds, m=12, block_rows=64)
+    d = ops.tournament(probs, seeds, m=12, block_rows=64, interpret=True)
     np.testing.assert_allclose(np.asarray(d.mean(0)), np.asarray(P),
                                atol=0.02)
 
@@ -91,7 +91,7 @@ def test_tournament_kernel_unbiased():
 def test_gumbel_argmax_property(b, v, seed):
     probs = _probs(seed % 1013, (b, v))
     seeds = _seeds(seed % 509, (b,))
-    tok_k, u_k = ops.gumbel_argmax(probs, seeds)
+    tok_k, u_k = ops.gumbel_argmax(probs, seeds, interpret=True)
     tok_r, u_r = ref.gumbel_argmax_ref(probs, seeds)
     assert np.array_equal(np.asarray(tok_k), np.asarray(tok_r))
     assert np.all((np.asarray(u_k) > 0) & (np.asarray(u_k) < 1))
@@ -106,7 +106,7 @@ def test_spec_verify_property(b, k, v, seed):
     toks = jax.random.randint(jax.random.key(seed % 887), (b, k), 0, v)
     u = jax.random.uniform(jax.random.key(seed % 907), (b, k))
     seeds = _seeds(seed % 911, (b, k))
-    nk, ak, rk, _ = ops.spec_verify(p, q, toks, u, seeds)
+    nk, ak, rk, _ = ops.spec_verify(p, q, toks, u, seeds, interpret=True)
     nr, ar, rr, _ = ref.spec_verify_ref(p, q, toks, u, seeds)
     assert np.array_equal(np.asarray(nk), np.asarray(nr))
     assert np.array_equal(np.asarray(ak), np.asarray(ar))
@@ -341,3 +341,17 @@ def test_wkv_step_property_state_carries(seed, b, hd):
                                np.asarray(y_r), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_r), rtol=1e-5,
                                atol=1e-6)
+
+
+def test_kernel_mode_dispatch(monkeypatch):
+    """CPU runs the mirrors, TPU the Mosaic kernels, the interpreter only
+    on request; any other backend raises instead of falling back."""
+    import jax
+    assert ops.kernel_mode() == "mirror"            # this suite runs on CPU
+    assert ops.kernel_mode(True) == "interpret"
+    assert ops.kernel_mode(False) == "mosaic"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.kernel_mode() == "mosaic"
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.kernel_mode()

@@ -24,9 +24,9 @@ def _rand_paged(rng, *, B=3, n_pages=17, page_size=8, max_pages=4, Hkv=2,
     import jax.numpy as jnp
     q = jnp.asarray(rng.standard_normal((B, Sq, H, hd)), jnp.float32)
     k_pool = jnp.asarray(
-        rng.standard_normal((n_pages, page_size, Hkv, hd)), jnp.float32)
+        rng.standard_normal((n_pages, Hkv, page_size, hd)), jnp.float32)
     v_pool = jnp.asarray(
-        rng.standard_normal((n_pages, page_size, Hkv, hd)), jnp.float32)
+        rng.standard_normal((n_pages, Hkv, page_size, hd)), jnp.float32)
     perm = rng.permutation(np.arange(1, n_pages))[:B * max_pages]
     table = jnp.asarray(perm.reshape(B, max_pages).astype(np.int32))
     return q, k_pool, v_pool, table

@@ -10,8 +10,8 @@ from repro import sharding as sh
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config
 from repro.models import model as M
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_spec_tree(tree_abs, specs, mesh):
@@ -115,7 +115,7 @@ def test_host_mesh_lowering_end_to_end():
     NS = jax.sharding.NamedSharding
     opt_abs = {"m": params, "v": params,
                "step": jax.ShapeDtypeStruct((), jnp.int32)}
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=(
             jax.tree.map(lambda s: NS(mesh, s), p_spec),
             {"m": jax.tree.map(lambda s: NS(mesh, s), p_spec),
@@ -123,3 +123,15 @@ def test_host_mesh_lowering_end_to_end():
              "step": NS(mesh, P())},
             None)).lower(params, opt_abs, batch)
         lowered.compile()
+
+
+def test_host_mesh_refuses_more_devices_than_present():
+    """A requested mesh is never silently clamped to the devices present."""
+    from repro.launch.mesh import make_host_mesh
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(data=n + 1, model=1)
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(data=1, model=2 * n)
+    assert dict(make_host_mesh(data=n, model=1).shape) == {
+        "data": n, "model": 1}
