@@ -586,9 +586,11 @@ def make_spec_step(tcfg: ModelConfig, dcfg: ModelConfig, scfg: SpecConfig,
             chk = drafter.checkpoint(d_cache)
             return (d_cache, tok, window), (tok, q_full, ctx_h, seen, chk)
 
-        (d_cache, _, window_k), \
-            (draft_toks, q_fulls, ctx_hs, seens, d_chks) = \
-            jax.lax.scan(draft_body, (d_cache, last, window), None, length=K)
+        with jax.named_scope("spec_draft"):
+            (d_cache, _, window_k), \
+                (draft_toks, q_fulls, ctx_hs, seens, d_chks) = \
+                jax.lax.scan(draft_body, (d_cache, last, window), None,
+                             length=K)
         draft_toks = draft_toks.T                       # (B, K)
         q_fulls = q_fulls.transpose(1, 0, 2)            # (B, K, V)
         ctx_hs = ctx_hs.T                               # (B, K)
@@ -600,178 +602,194 @@ def make_spec_step(tcfg: ModelConfig, dcfg: ModelConfig, scfg: SpecConfig,
         seen_bonus = seen_bonus | strength_gate(keys, ctx_bonus, strength)
 
         # ---- 2. target verification ----------------------------------------
-        fed = jnp.concatenate([last[:, None], draft_toks], axis=1)  # (B,K+1)
-        t_logits, t_cache, t_chks = _run_target(t_params, tcfg, fed, t_cache)
-        p_fulls = jax.nn.softmax(t_logits.astype(jnp.float32) / temp, -1)
+        with jax.named_scope("spec_target"):
+            fed = jnp.concatenate([last[:, None], draft_toks], axis=1)
+            t_logits, t_cache, t_chks = _run_target(t_params, tcfg, fed,
+                                                    t_cache)
+            p_fulls = jax.nn.softmax(t_logits.astype(jnp.float32) / temp, -1)
 
-        # ---- 3. acceptance coins -------------------------------------------
-        if scfg.accept == "pseudorandom":
-            u = prf.accept_uniform(keys[:, None], ctx_hs)   # (B, K)
-        else:
-            # fresh coins, still per-slot: each row folds its own key word
-            # so mixed-key batches stay slot-isolated even in standard mode
-            u = jax.vmap(lambda kw: jax.random.uniform(
-                jax.random.fold_in(jax.random.key(kw), state["step_idx"]),
-                (K,)))(keys)
+        with jax.named_scope("spec_tail"):
+            # ---- 3. acceptance coins ----------------------------------------
+            if scfg.accept == "pseudorandom":
+                u = prf.accept_uniform(keys[:, None], ctx_hs)   # (B, K)
+            else:
+                # fresh coins, still per-slot: each row folds its own key word
+                # so mixed-key batches stay slot-isolated even in standard mode
+                u = jax.vmap(lambda kw: jax.random.uniform(
+                    jax.random.fold_in(jax.random.key(kw), state["step_idx"]),
+                    (K,)))(keys)
 
-        all_hashes = jnp.concatenate([ctx_hs, ctx_bonus[:, None]], axis=1)
-        all_seen = jnp.concatenate([seens, seen_bonus[:, None]], axis=1)
+            all_hashes = jnp.concatenate([ctx_hs, ctx_bonus[:, None]], axis=1)
+            all_seen = jnp.concatenate([seens, seen_bonus[:, None]], axis=1)
 
-        if fused:
-            # ---- 4. fused verify + residual/bonus (Pallas) -----------------
-            # The kernel gathers p/q of the drafts, computes the prefix
-            # acceptance and samples the single emitted extra token in
-            # VMEM — one Gumbel race or one m-round tournament per row,
-            # per the scheme's FusedTail declaration — re-deriving every
-            # per-slot seed from the (B,) key row under the static
-            # ``tail_streams`` and switching to the plain-stream seed on
-            # ``seen`` contexts.  No host-derived seed tensors cross HBM.
-            axes = SHR.dp_axes(mesh, B) if mesh is not None else None
-            live_i = None if live is None else live.astype(jnp.int32)
-            n_acc, prefix_i, extra, _ = KOPS.spec_verify_wm(
-                p_fulls, q_fulls, draft_toks, u, keys, all_hashes,
-                all_seen, live_i, streams=tail_streams, tail=tail_spec,
-                mesh=mesh if axes else None, batch_axes=axes)
-            prefix = prefix_i.astype(bool)
-        else:
-            # ---- 4. jnp tail (decoder-generic reference path) --------------
-            p_of_draft = jax.vmap(_gather_probs, in_axes=(1, 1), out_axes=1)(
-                p_fulls[:, :K], draft_toks)               # (B, K)
-            q_of_draft = jax.vmap(_gather_probs, in_axes=(1, 1), out_axes=1)(
-                q_fulls, draft_toks)                      # (B, K)
-            a = jnp.minimum(1.0, p_of_draft / jnp.maximum(q_of_draft, EPS))
-            ok = u < a
-            prefix = jnp.cumprod(ok.astype(jnp.int32), axis=-1).astype(bool)
-            n_acc = prefix.sum(axis=-1).astype(jnp.int32)  # (B,)
-            all_ok = n_acc == K
-            # raw (P−Q)_+ rows: the Gumbel race is scale-invariant and the
-            # tournament decoder normalizes internally at the padded-lane
-            # extent, so no (extent-sensitive) normalization happens here
-            resid = jnp.maximum(p_fulls[:, :K] - q_fulls, 0.0)  # (B, K, V)
-            resid_toks = jax.vmap(
-                lambda pr, ch, sn: _wm_sample_batch(
-                    dec, pr, keys, ch, prf.STREAM_TARGET, sn,
-                    prf.STREAM_PLAIN + 2),
-                in_axes=(1, 1, 1), out_axes=1)(resid, ctx_hs, seens)
-            bonus_tok = _wm_sample_batch(dec, p_fulls[:, K], keys, ctx_bonus,
-                                         prf.STREAM_TARGET, seen_bonus,
-                                         prf.STREAM_PLAIN + 3)    # (B,)
-            extra = jnp.where(
-                all_ok, bonus_tok,
-                jnp.take_along_axis(resid_toks,
-                                    jnp.minimum(n_acc, K - 1)[:, None],
-                                    axis=1)[:, 0])
+            if fused:
+                # ---- 4. fused verify + residual/bonus (Pallas) --------------
+                # The kernel gathers p/q of the drafts, computes the prefix
+                # acceptance and samples the single emitted extra token in
+                # VMEM — one Gumbel race or one m-round tournament per row,
+                # per the scheme's FusedTail declaration — re-deriving every
+                # per-slot seed from the (B,) key row under the static
+                # ``tail_streams`` and switching to the plain-stream seed on
+                # ``seen`` contexts.  No host-derived seed tensors cross HBM.
+                axes = SHR.dp_axes(mesh, B) if mesh is not None else None
+                live_i = None if live is None else live.astype(jnp.int32)
+                n_acc, prefix_i, extra, _ = KOPS.spec_verify_wm(
+                    p_fulls, q_fulls, draft_toks, u, keys, all_hashes,
+                    all_seen, live_i, streams=tail_streams, tail=tail_spec,
+                    mesh=mesh if axes else None, batch_axes=axes)
+                prefix = prefix_i.astype(bool)
+            else:
+                # ---- 4. jnp tail (decoder-generic reference path) -----------
+                gather = jax.vmap(_gather_probs, in_axes=(1, 1), out_axes=1)
+                p_of_draft = gather(p_fulls[:, :K], draft_toks)   # (B, K)
+                q_of_draft = gather(q_fulls, draft_toks)          # (B, K)
+                a = jnp.minimum(1.0, p_of_draft / jnp.maximum(q_of_draft, EPS))
+                ok = u < a
+                prefix = jnp.cumprod(ok.astype(jnp.int32),
+                                     axis=-1).astype(bool)
+                n_acc = prefix.sum(axis=-1).astype(jnp.int32)  # (B,)
+                all_ok = n_acc == K
+                # raw (P−Q)_+ rows: the Gumbel race is scale-invariant and
+                # the tournament decoder normalizes internally at the
+                # padded-lane extent, so no (extent-sensitive) normalization
+                # happens here
+                resid = jnp.maximum(p_fulls[:, :K] - q_fulls, 0.0)  # (B, K, V)
+                resid_toks = jax.vmap(
+                    lambda pr, ch, sn: _wm_sample_batch(
+                        dec, pr, keys, ch, prf.STREAM_TARGET, sn,
+                        prf.STREAM_PLAIN + 2),
+                    in_axes=(1, 1, 1), out_axes=1)(resid, ctx_hs, seens)
+                bonus_tok = _wm_sample_batch(
+                    dec, p_fulls[:, K], keys, ctx_bonus, prf.STREAM_TARGET,
+                    seen_bonus, prf.STREAM_PLAIN + 3)             # (B,)
+                extra = jnp.where(
+                    all_ok, bonus_tok,
+                    jnp.take_along_axis(resid_toks,
+                                        jnp.minimum(n_acc, K - 1)[:, None],
+                                        axis=1)[:, 0])
 
-        # ---- 5. assemble outputs -------------------------------------------
-        out = jnp.zeros((B, K + 1), jnp.int32)
-        out = out.at[:, :K].set(jnp.where(prefix, draft_toks, 0))
-        out = jax.vmap(lambda o, n, e: o.at[n].set(e))(out, n_acc, extra)
-        out_len = n_acc + 1
-        if eos_id is not None:
-            # EOS cut *inside the step*, before the commit: truncate the
-            # emission at the first EOS so every piece of committed state
-            # (window, last + its metadata, history, cache positions,
-            # recurrent rollback) ends exactly at the EOS token — a
-            # resumed or re-admitted slot then continues from precisely
-            # the delivered stream, never from dropped post-EOS tokens.
-            sidx = jnp.arange(K + 1)[None, :]
-            is_eos = (out == eos_id) & (sidx < out_len[:, None])
-            first = jnp.where(is_eos.any(axis=1),
-                              jnp.argmax(is_eos, axis=1), K + 1)
-            out_len = jnp.minimum(out_len, (first + 1).astype(jnp.int32))
-            # accepted AND emitted (the drafts dropped by the cut were
-            # verified but never delivered)
-            n_acc = jnp.minimum(n_acc, out_len)
-            out = jnp.where(sidx < out_len[:, None], out, 0)
-        from_draft = jnp.arange(K + 1)[None, :] < n_acc[:, None]
+            # ---- 5. assemble outputs ----------------------------------------
+            out = jnp.zeros((B, K + 1), jnp.int32)
+            out = out.at[:, :K].set(jnp.where(prefix, draft_toks, 0))
+            out = jax.vmap(lambda o, n, e: o.at[n].set(e))(out, n_acc, extra)
+            out_len = n_acc + 1
+            if eos_id is not None:
+                # EOS cut *inside the step*, before the commit: truncate the
+                # emission at the first EOS so every piece of committed state
+                # (window, last + its metadata, history, cache positions,
+                # recurrent rollback) ends exactly at the EOS token — a
+                # resumed or re-admitted slot then continues from precisely
+                # the delivered stream, never from dropped post-EOS tokens.
+                sidx = jnp.arange(K + 1)[None, :]
+                is_eos = (out == eos_id) & (sidx < out_len[:, None])
+                first = jnp.where(is_eos.any(axis=1),
+                                  jnp.argmax(is_eos, axis=1), K + 1)
+                out_len = jnp.minimum(out_len, (first + 1).astype(jnp.int32))
+                # accepted AND emitted (the drafts dropped by the cut were
+                # verified but never delivered)
+                n_acc = jnp.minimum(n_acc, out_len)
+                out = jnp.where(sidx < out_len[:, None], out, 0)
+            from_draft = jnp.arange(K + 1)[None, :] < n_acc[:, None]
 
         # per-slot detection statistics of the emitted tokens under BOTH
         # candidate streams (what the detectors consume) — O(stat_dim) per
         # token off the counter PRF, so served records need no recovery
         # pass.  Streams here are the detection-time constants, matching
         # ``Decoder.recover_stats`` bit-exactly.
-        V = q_fulls.shape[-1]
-        yd_seeds = prf.wm_seed(keys[:, None], all_hashes, prf.STREAM_DRAFT)
-        yt_seeds = prf.wm_seed(keys[:, None], all_hashes, prf.STREAM_TARGET)
-        y_d = _token_stat_batch(dec, yd_seeds, out, V)    # (B, K+1, S)
-        y_t = _token_stat_batch(dec, yt_seeds, out, V)
+        with jax.named_scope("spec_stats"):
+            V = q_fulls.shape[-1]
+            yd_seeds = prf.wm_seed(keys[:, None], all_hashes,
+                                   prf.STREAM_DRAFT)
+            yt_seeds = prf.wm_seed(keys[:, None], all_hashes,
+                                   prf.STREAM_TARGET)
+            y_d = _token_stat_batch(dec, yd_seeds, out, V)    # (B, K+1, S)
+            y_t = _token_stat_batch(dec, yt_seeds, out, V)
 
-        # ---- 6. commit -------------------------------------------------------
-        t_cache = _rollback(t_cache, t_chks, t_pos0, out_len)
-        # draft side: the K-th-token catch-up re-feed + rollback to the
-        # accepted length, per the drafter's state semantics (pos-only for
-        # KV caches, checkpoint selection for recurrent state).
-        d_cache = drafter.commit(d_params, dcfg, draft_toks[:, K - 1],
-                                 d_cache, d_chks, d_pos0, out_len)
-        # rebuild window/last from the *emitted* tokens
-        full = jnp.concatenate([window, out], axis=1)     # (B, c+K+1)
-        idx = out_len[:, None] + jnp.arange(c)[None, :]   # window ending at n'
-        new_window = jnp.take_along_axis(full, idx, axis=1)
-        last_i = (out_len - 1)[:, None]
-        new_last = jnp.take_along_axis(out, last_i, axis=1)[:, 0]
-        # slot-0 metadata for the next buffer (chained-generate resume):
-        # the final emitted slot is always the extra (target) token, so only
-        # its context hash, recorded coin and seen flag need carrying.
-        u_rec = jnp.concatenate([u, jnp.zeros((B, 1), jnp.float32)], axis=1)
-        new_last_ctx = jnp.take_along_axis(all_hashes, last_i, axis=1)[:, 0]
-        new_last_u = jnp.take_along_axis(u_rec, last_i, axis=1)[:, 0]
-        new_last_msk = jnp.take_along_axis(all_seen, last_i, axis=1)[:, 0]
-        new_last_yd = jax.vmap(lambda y, n: y[n])(y_d, out_len - 1)
-        new_last_yt = jax.vmap(lambda y, n: y[n])(y_t, out_len - 1)
-        # history append for emitted, previously-unseen contexts — a masked
-        # scatter: slot s lands at (hist_n + #adds-before-s) mod H; skipped
-        # slots are routed to a trash column that is sliced off.
-        if scfg.mask_repeated:
-            emitted = jnp.arange(K + 1)[None, :] < out_len[:, None]
-            add = emitted & ~all_seen                     # (B, K+1)
-            H = hist.shape[1]
-            off = jnp.cumsum(add.astype(jnp.int32), axis=1) - add
-            pos = jnp.where(add, (hist_n[:, None] + off) % H, H)
-            rows = jnp.arange(B)[:, None]
-            padded = jnp.concatenate(
-                [hist, jnp.zeros((B, 1), hist.dtype)], axis=1)
-            hist = padded.at[rows, pos].set(
-                jnp.where(add, all_hashes, 0))[:, :H]
-            hist_n = hist_n + add.sum(axis=1).astype(jnp.int32)
+        with jax.named_scope("spec_commit"):
+            # ---- 6. commit --------------------------------------------------
+            t_cache = _rollback(t_cache, t_chks, t_pos0, out_len)
+            # draft side: the K-th-token catch-up re-feed + rollback to the
+            # accepted length, per the drafter's state semantics (pos-only for
+            # KV caches, checkpoint selection for recurrent state).
+            with jax.named_scope("spec_draft_refeed"):
+                d_cache = drafter.commit(
+                    d_params, dcfg, draft_toks[:, K - 1], d_cache, d_chks,
+                    d_pos0, out_len)
+            # rebuild window/last from the *emitted* tokens
+            full = jnp.concatenate([window, out], axis=1)     # (B, c+K+1)
+            # the window ending at n'
+            idx = out_len[:, None] + jnp.arange(c)[None, :]
+            new_window = jnp.take_along_axis(full, idx, axis=1)
+            last_i = (out_len - 1)[:, None]
+            new_last = jnp.take_along_axis(out, last_i, axis=1)[:, 0]
+            # slot-0 metadata for the next buffer (chained-generate resume):
+            # the final emitted slot is always the extra (target) token, so
+            # only its context hash, recorded coin and seen flag need
+            # carrying.
+            u_rec = jnp.concatenate([u, jnp.zeros((B, 1), jnp.float32)],
+                                    axis=1)
+            new_last_ctx = jnp.take_along_axis(all_hashes, last_i,
+                                               axis=1)[:, 0]
+            new_last_u = jnp.take_along_axis(u_rec, last_i, axis=1)[:, 0]
+            new_last_msk = jnp.take_along_axis(all_seen, last_i, axis=1)[:, 0]
+            new_last_yd = jax.vmap(lambda y, n: y[n])(y_d, out_len - 1)
+            new_last_yt = jax.vmap(lambda y, n: y[n])(y_t, out_len - 1)
+            # history append for emitted, previously-unseen contexts — a
+            # masked scatter: slot s lands at (hist_n + #adds-before-s) mod
+            # H; skipped slots are routed to a trash column that is sliced
+            # off.
+            if scfg.mask_repeated:
+                emitted = jnp.arange(K + 1)[None, :] < out_len[:, None]
+                add = emitted & ~all_seen                     # (B, K+1)
+                H = hist.shape[1]
+                off = jnp.cumsum(add.astype(jnp.int32), axis=1) - add
+                pos = jnp.where(add, (hist_n[:, None] + off) % H, H)
+                rows = jnp.arange(B)[:, None]
+                padded = jnp.concatenate(
+                    [hist, jnp.zeros((B, 1), hist.dtype)], axis=1)
+                hist = padded.at[rows, pos].set(
+                    jnp.where(add, all_hashes, 0))[:, :H]
+                hist_n = hist_n + add.sum(axis=1).astype(jnp.int32)
 
-        new_state = dict(state, t_cache=t_cache, d_cache=d_cache,
-                         window=new_window, last=new_last,
-                         last_ctx=new_last_ctx, last_u=new_last_u,
-                         last_msk=new_last_msk,
-                         last_yd=new_last_yd, last_yt=new_last_yt,
-                         n_committed=state["n_committed"] + out_len,
-                         hist=hist, hist_n=hist_n,
-                         step_idx=state["step_idx"] + 1)
-        if live is not None:
-            # Freeze non-live (drained/free) slots: their per-slot state rows
-            # revert to the pre-step values so a drained slot can resume or
-            # be re-admitted bit-exactly.  KV cache rows need no select —
-            # a frozen slot's position does not advance, so the garbage this
-            # step wrote beyond ``pos`` is overwritten before it is ever
-            # attended (attention is position-gated); recurrent states have
-            # no position gate, so they do revert.
-            dead = ~live
+            new_state = dict(state, t_cache=t_cache, d_cache=d_cache,
+                             window=new_window, last=new_last,
+                             last_ctx=new_last_ctx, last_u=new_last_u,
+                             last_msk=new_last_msk,
+                             last_yd=new_last_yd, last_yt=new_last_yt,
+                             n_committed=state["n_committed"] + out_len,
+                             hist=hist, hist_n=hist_n,
+                             step_idx=state["step_idx"] + 1)
+            if live is not None:
+                # Freeze non-live (drained/free) slots: their per-slot state
+                # rows revert to the pre-step values so a drained slot can
+                # resume or be re-admitted bit-exactly.  KV cache rows need
+                # no select — a frozen slot's position does not advance, so
+                # the garbage this step wrote beyond ``pos`` is overwritten
+                # before it is ever attended (attention is position-gated);
+                # recurrent states have no position gate, so they do revert.
+                dead = ~live
 
-            def keep0(new, old):      # batch-leading (engine vectors)
-                m = dead.reshape((-1,) + (1,) * (new.ndim - 1))
-                return jnp.where(m, old, new)
+                def keep0(new, old):      # batch-leading (engine vectors)
+                    m = dead.reshape((-1,) + (1,) * (new.ndim - 1))
+                    return jnp.where(m, old, new)
 
-            def keep1(new, old):      # (L, B, ...) cache entries
-                m = dead.reshape((1, -1) + (1,) * (new.ndim - 2))
-                return jnp.where(m, old, new)
+                def keep1(new, old):      # (L, B, ...) cache entries
+                    m = dead.reshape((1, -1) + (1,) * (new.ndim - 2))
+                    return jnp.where(m, old, new)
 
-            for k in ("window", "last", "last_ctx", "last_u", "last_msk",
-                      "last_yd", "last_yt", "n_committed", "hist",
-                      "hist_n"):
-                new_state[k] = keep0(new_state[k], state[k])
-            for cn, state_keys in (("t_cache", RECURRENT_KEYS),
-                                   ("d_cache", drafter.state_keys)):
-                cache_new = dict(new_state[cn])
-                cache_new["pos"] = keep0(cache_new["pos"], state[cn]["pos"])
-                for rk in state_keys:
-                    if rk in cache_new:
-                        cache_new[rk] = keep1(cache_new[rk], state[cn][rk])
-                new_state[cn] = cache_new
+                for k in ("window", "last", "last_ctx", "last_u", "last_msk",
+                          "last_yd", "last_yt", "n_committed", "hist",
+                          "hist_n"):
+                    new_state[k] = keep0(new_state[k], state[k])
+                for cn, state_keys in (("t_cache", RECURRENT_KEYS),
+                                       ("d_cache", drafter.state_keys)):
+                    cache_new = dict(new_state[cn])
+                    cache_new["pos"] = keep0(cache_new["pos"],
+                                             state[cn]["pos"])
+                    for rk in state_keys:
+                        if rk in cache_new:
+                            cache_new[rk] = keep1(cache_new[rk], state[cn][rk])
+                    new_state[cn] = cache_new
         return new_state, StepOutput(
             out_tokens=out, out_len=out_len, n_accepted=n_acc,
             from_draft=from_draft, u=u, ctx_hashes=all_hashes,

@@ -86,6 +86,7 @@ from typing import (Any, Callable, Deque, Dict, Iterator, List, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -626,6 +627,15 @@ class Scheduler:
         self._t_submit: Dict[int, float] = {}
         self._arrivals: Dict[int, List[float]] = {}
         self.n_rounds = 0
+        # live counters (``stats()``), kept from each round's snapshot:
+        # sync rounds and loop steps run, and the alive slot-steps and
+        # accepted draft tokens the slots gained since the last snapshot
+        # (host mirrors of the per-slot device counters, zeroed where a
+        # slot is armed, as the device rows are)
+        self._rounds = self._loop_steps = 0
+        self._live_slot_steps = self._accepted_live = 0
+        self._alive_host = np.zeros((batch,), np.int64)
+        self._acc_host = np.zeros((batch,), np.int64)
 
         self._drafter = get_drafter(dcfg)
         self.paged = page_size is not None
@@ -845,12 +855,22 @@ class Scheduler:
         self._slot_tier[b] = req.tier
         self._slot_pooled[b] = pooled
 
-    def _admit(self) -> int:
+    def _arm(self, b: int) -> None:
+        """Host side of arming slot ``b`` (``_admit_fn`` / ``_finalize_fn``
+        zero its device flags and counters): it decodes from its first
+        token, none streamed yet, and its live-counter mirrors restart."""
+        self.slots[b].phase = DECODING
+        self._streamed[b] = 0
+        self._done_host[b] = False
+        self._alive_host[b] = self._acc_host[b] = 0
+        self.admit_order.append(self.slots[b].request.uid)
+
+    def _admit(self) -> List[int]:
         """Fill every FREE slot from the queue head (FIFO); returns the
-        number of admissions."""
+        admitted uids."""
         if self.paged:
             return self._admit_paged()
-        n = 0
+        uids = []
         for b, slot in enumerate(self.slots):
             if not self.queue:
                 break
@@ -870,12 +890,9 @@ class Scheduler:
             self.carry = self._admit_jit(self.carry, sub, jnp.int32(b),
                                          jnp.int32(req.n_tokens))
             self.n_tok[b] = req.n_tokens
-            slot.phase = DECODING
-            self._streamed[b] = 0
-            self._done_host[b] = False
-            self.admit_order.append(req.uid)
-            n += 1
-        return n
+            self._arm(b)
+            uids.append(req.uid)
+        return uids
 
     # -- paged admission: page tables + chunked prefill --------------------
 
@@ -990,7 +1007,7 @@ class Scheduler:
             alive_steps=carry["alive_steps"].at[b].set(0),
         )
 
-    def _admit_paged(self) -> int:
+    def _admit_paged(self) -> List[int]:
         """Reserve pages + page tables for queued prompts (FIFO with
         head-of-line blocking on pool space — never reorders) and mark
         their slots PREFILLING; the actual prompt tokens stream in via
@@ -1009,7 +1026,7 @@ class Scheduler:
         (which can raise) runs before any page is allocated or any slot
         state is touched; the share/alloc that follow cannot fail (free
         space was just checked and the scheduler is single-threaded)."""
-        n = 0
+        uids = []
         for b, slot in enumerate(self.slots):
             if not self.queue:
                 break
@@ -1042,8 +1059,8 @@ class Scheduler:
                 self._prefix.pages_saved += len(shared)
                 self.events.append(
                     ("admit_shared", req.uid, int(self._prefill_base[b])))
-            n += 1
-        return n
+            uids.append(req.uid)
+        return uids
 
     def _prefill_step(self) -> None:
         """Advance every PREFILLING slot by ONE prompt chunk (so a long
@@ -1051,52 +1068,53 @@ class Scheduler:
         chunk also runs the finalize (first-token sample) and flips it to
         DECODING."""
         for b, slot in enumerate(self.slots):
-            if slot.phase != PREFILLING:
-                continue
-            req = slot.request
-            S0, ck = len(req.prompt), self.prefill_chunk
-            i = int(self._chunk_cursor[b])
-            # prefix-cache hits are already resident: chunk i covers
-            # prompt[base + i*ck : base + (i+1)*ck] (base is 0 without a
-            # cache; the share-eligibility rule keeps base <= S0 - 1, so
-            # every slot prefills >= 1 token and finalize always has its
-            # last-position logits)
-            start = int(self._prefill_base[b]) + i * ck
-            chunk = np.zeros((ck,), np.int32)
-            chunk[:min(ck, S0 - start)] = req.prompt[start:start + ck]
-            new_pos = min(start + ck, S0)
-            self.carry, logits = self._chunk_jit(
-                self.t_params, self.d_params, self.carry,
-                jnp.asarray(chunk), jnp.int32(b), jnp.int32(start),
-                jnp.int32(new_pos))
-            self.events.append(("admit_chunk", req.uid, i))
-            self._chunk_cursor[b] = i + 1
-            if new_pos < S0:
-                continue
-            c = self.scfg.ctx_window
-            window = np.zeros((c,), np.int32)
-            window[max(c - S0, 0):] = req.prompt[-c:]
-            self.carry = self._finalize_jit(
-                self.carry, jnp.uint32(self._slot_key[b]),
-                jnp.float32(self._slot_strength[b]), logits, jnp.int32(b),
-                jnp.int32(S0 - 1 - start), jnp.asarray(window),
-                jnp.int32(req.n_tokens))
-            self.n_tok[b] = req.n_tokens
-            slot.phase = DECODING
-            self._streamed[b] = 0
-            # finalize leaves pos at the host-known S0: _ensure_pages can
-            # plan the slot's first decode chunk without a device poll
-            self._pos_host[b] = S0
-            self._done_host[b] = False
-            self.admit_order.append(req.uid)
-            self.events.append(("finalize", req.uid))
-            if self._prefix is not None:
-                # every share-eligible block is now fully written: hand
-                # the new full-prefix pages to the cache (it takes its
-                # own refs, so they survive this slot's flush)
-                self._prefix.insert_chain(req.prompt,
-                                          self._slot_hit_digests[b],
-                                          self._slot_pages[b])
+            if slot.phase == PREFILLING:
+                with TraceAnnotation("serve.prefill", uid=slot.request.uid,
+                                     chunk=int(self._chunk_cursor[b])):
+                    self._prefill_chunk(b)
+
+    def _prefill_chunk(self, b: int) -> None:
+        """Slot ``b``'s next prompt chunk, and its finalize after the last."""
+        req = self.slots[b].request
+        S0, ck = len(req.prompt), self.prefill_chunk
+        i = int(self._chunk_cursor[b])
+        # prefix-cache hits are already resident: chunk i covers
+        # prompt[base + i*ck : base + (i+1)*ck] (base is 0 without a cache;
+        # the share-eligibility rule keeps base <= S0 - 1, so every slot
+        # prefills >= 1 token and finalize always has its last-position
+        # logits)
+        start = int(self._prefill_base[b]) + i * ck
+        chunk = np.zeros((ck,), np.int32)
+        chunk[:min(ck, S0 - start)] = req.prompt[start:start + ck]
+        new_pos = min(start + ck, S0)
+        self.carry, logits = self._chunk_jit(
+            self.t_params, self.d_params, self.carry,
+            jnp.asarray(chunk), jnp.int32(b), jnp.int32(start),
+            jnp.int32(new_pos))
+        self.events.append(("admit_chunk", req.uid, i))
+        self._chunk_cursor[b] = i + 1
+        if new_pos < S0:
+            return
+        c = self.scfg.ctx_window
+        window = np.zeros((c,), np.int32)
+        window[max(c - S0, 0):] = req.prompt[-c:]
+        self.carry = self._finalize_jit(
+            self.carry, jnp.uint32(self._slot_key[b]),
+            jnp.float32(self._slot_strength[b]), logits, jnp.int32(b),
+            jnp.int32(S0 - 1 - start), jnp.asarray(window),
+            jnp.int32(req.n_tokens))
+        self.n_tok[b] = req.n_tokens
+        self._arm(b)
+        # finalize leaves pos at the host-known S0: _ensure_pages can
+        # plan the slot's first decode chunk without a device poll
+        self._pos_host[b] = S0
+        self.events.append(("finalize", req.uid))
+        if self._prefix is not None:
+            # every share-eligible block is now fully written: hand the new
+            # full-prefix pages to the cache (it takes its own refs, so
+            # they survive this slot's flush)
+            self._prefix.insert_chain(req.prompt, self._slot_hit_digests[b],
+                                      self._slot_pages[b])
 
     def _ensure_pages(self) -> None:
         """Grow every live DECODING slot's page run to cover the next
@@ -1159,7 +1177,7 @@ class Scheduler:
 
     _ROW_KEYS = ("toks", "fd", "us", "chs", "msk", "yd", "yt")
     _FLAG_KEYS = ("done", "eos", "lens", "total", "acc_total",
-                  "alive_steps")
+                  "alive_steps", "n_steps")
 
     def _row_fn(self, carry, b):
         """Jitted (compiles once — ``b`` is traced): slot ``b``'s full
@@ -1187,12 +1205,20 @@ class Scheduler:
 
     def _take_snapshot(self, handles) -> Dict[str, Any]:
         """The round's ONE batched host transfer, plus host-mirror
-        maintenance (``pos``/``done`` for ``_ensure_pages``)."""
+        maintenance (``pos``/``done`` for ``_ensure_pages``, the live
+        counters of ``stats()``)."""
         snap = jax.device_get(handles)
         flags = snap["flags"]
         if self.paged:
             self._pos_host[:] = np.asarray(flags["pos"])
         self._done_host[:] = np.asarray(flags["done"])
+        alive = np.asarray(flags["alive_steps"], np.int64)
+        acc = np.asarray(flags["acc_total"], np.int64)
+        self._live_slot_steps += int((alive - self._alive_host).sum())
+        self._accepted_live += int((acc - self._acc_host).sum())
+        self._alive_host[:] = alive
+        self._acc_host[:] = acc
+        self._loop_steps = int(flags["n_steps"])
         return snap
 
     def _stream_events(self, snap, t_now: float
@@ -1248,6 +1274,7 @@ class Scheduler:
             req = slot.request
             arrivals = np.asarray(self._arrivals.pop(req.uid, []),
                                   np.float64)
+            self._t_submit.pop(req.uid, None)
             res = RequestResult(
                 uid=req.uid, tokens=row["toks"],
                 src=row["fd"], u=row["us"],
@@ -1336,7 +1363,8 @@ class Scheduler:
         if self.paged:
             limit += self._total_chunks
         rounds = 0
-        self._admit()
+        with TraceAnnotation("serve.admit") as span:
+            self._set_uids(span, self._admit())
         self._check_paged_deadlock()
         while self.queue or self._active():
             rounds += 1
@@ -1346,22 +1374,42 @@ class Scheduler:
                     f"scheduler stalled after {rounds} sync rounds "
                     f"(queue={len(self.queue)}, "
                     f"slots={[s.phase for s in self.slots]})")
-            if self.paged:
-                self._prefill_step()
+            self._rounds += 1
+            with TraceAnnotation("serve.round", round=self._rounds):
+                yield from self._round()
+
+    def _round(self) -> Iterator[Tuple[int, int, dict]]:
+        """One sync round of ``run_stream``, each phase in its own
+        profiler span (recorded only while a trace is active)."""
+        if self.paged:
+            self._prefill_step()
+            with TraceAnnotation("serve.pages"):
                 self._ensure_pages()
-            if self.overlap:
-                # gathers enqueue BEFORE the chunk: device executes them
-                # first, so the transfer below never waits on the chunk
+        if self.overlap:
+            # gathers enqueue BEFORE the chunk: device executes them
+            # first, so the transfer below never waits on the chunk
+            with TraceAnnotation("serve.snapshot"):
                 handles = self._snap_handles(self.carry)
-                self._run_chunk()
-            else:
-                self._run_chunk()
+        with TraceAnnotation("serve.dispatch"):
+            self._run_chunk()
+        with TraceAnnotation("serve.snapshot"):
+            if not self.overlap:
                 handles = self._snap_handles(self.carry)
             snap = self._take_snapshot(handles)
+        with TraceAnnotation("serve.stream"):
             yield from self._stream_events(snap, time.perf_counter())
-            self._flush(snap)
-            self._admit()
-            self._check_paged_deadlock()
+        with TraceAnnotation("serve.flush") as span:
+            self._set_uids(span, [r.uid for r in self._flush(snap)])
+        with TraceAnnotation("serve.admit") as span:
+            self._set_uids(span, self._admit())
+        self._check_paged_deadlock()
+
+    @staticmethod
+    def _set_uids(span, uids: List[int]) -> None:
+        """Tag a flush/admit span with the requests it served (space-
+        separated: the profiler splits span arguments at commas)."""
+        if uids:
+            span.set_metadata(uids=" ".join(map(str, uids)))
 
     def run(self) -> List[RequestResult]:
         """Drain the queue: admit → decode chunk → flush, until every
@@ -1396,12 +1444,20 @@ class Scheduler:
 
     def stats(self) -> Dict[str, float]:
         """Cumulative honest serving stats over flushed requests (drained
-        slots never count toward the denominators)."""
+        slots never count toward the denominators), and live counters kept
+        from every round's snapshot, which move while requests run:
+        ``rounds`` (sync rounds), ``loop_steps`` (the loop's ``n_steps``),
+        ``live_slot_steps`` and ``accepted_live`` (alive slot-steps and
+        accepted draft tokens, over every request served or in flight)."""
         denom = max(self._alive, 1)
         out = {"served": float(len(self.results)),
                "aatps": self._acc / denom,
                "tokens_per_step": self._emitted / denom,
-               "alive_slot_steps": float(self._alive)}
+               "alive_slot_steps": float(self._alive),
+               "rounds": float(self._rounds),
+               "loop_steps": float(self._loop_steps),
+               "live_slot_steps": float(self._live_slot_steps),
+               "accepted_live": float(self._accepted_live)}
         ttfts = [r.ttft_s for r in self.results.values()
                  if r.ttft_s is not None]
         if ttfts:
